@@ -91,6 +91,10 @@ TPU_V5E = DeviceSpec("TPU v5e", "google", "tpu", 197e12, "bf16",
 TPU_V5E_ICI_BW = 50e9      # B/s per link
 TPU_V5E_DCN_BW = 25e9      # B/s inter-pod share per chip
 
+#: ``device_kind`` as JAX reports it -> spec. A kind not listed has no peaks
+#: here, and a chip run on it is an error, not a default.
+DEVICE_KINDS: Dict[str, DeviceSpec] = {"TPU v5 lite": TPU_V5E}
+
 
 def _dalek_node(name, cpu, gpu, ram, idle, susp, tdp, net=2.5):
     devs = (cpu,) + ((gpu,) if gpu else ())

@@ -52,49 +52,24 @@ class TraceStats:
         return dict(self.compile_counts)
 
 
-def _abstract_signature(args, kwargs):
-    """Hashable abstract signature of a call: treedef + per-leaf
-    (shape, dtype) for arrays, value identity for python statics."""
-    leaves, treedef = jax.tree.flatten((args, kwargs))
-
-    def describe(leaf):
-        if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
-            return (tuple(leaf.shape), str(leaf.dtype),
-                    bool(getattr(leaf, "weak_type", False)))
-        return ("py", type(leaf).__name__, repr(leaf))
-
-    return (treedef,) + tuple(describe(l) for l in leaves)
-
-
 def counting_jit(fn, name: str, stats: Optional[TraceStats] = None,
                  on_compile=None, **jit_kwargs):
     """``jax.jit(fn)`` wrapped with trace accounting.
 
     A call that grows the jit executable cache counts as one compile on
     ``stats`` (and fires ``on_compile(name)`` — the hook engines use to
-    surface compile activity through telemetry counters). The primary
-    detector is the cache-size delta around the call (exact and O(1)); when
-    that private accessor is unavailable the wrapper falls back to tracking
-    abstract input signatures, which costs a pytree flatten per call. The
+    surface compile activity through telemetry counters). The detector is
+    the executable-cache size around the call (exact and O(1)). The
     wrapped jitted function is exposed as ``wrapper.jitted``; AOT users
     call ``wrapper.lower(...)`` — a lower is a trace, so it records one
     compile on ``stats`` (the dryrun driver's explicit-compile path).
     """
     jitted = jax.jit(fn, **jit_kwargs)  # dalek: allow[bare-jit] counting_jit IS the tracked wrapper
-    cache_size = getattr(jitted, "_cache_size", None)
-    seen = set()
 
     def wrapper(*args, **kwargs):
-        if cache_size is not None:
-            before = cache_size()
-            out = jitted(*args, **kwargs)
-            new = cache_size() > before
-        else:
-            sig = _abstract_signature(args, kwargs)
-            new = sig not in seen
-            if new:
-                seen.add(sig)
-            out = jitted(*args, **kwargs)
+        before = jitted._cache_size()
+        out = jitted(*args, **kwargs)
+        new = jitted._cache_size() > before
         if stats is not None:
             stats.record(name, new)
         if new and on_compile is not None:

@@ -35,5 +35,10 @@ triad = counting_jit(_triad, "stream/triad", stats,
 
 def bytes_moved(op: str, a) -> int:
     n = a.size * a.dtype.itemsize
-    return {"read": n, "write": n, "copy": 2 * n, "scale": 2 * n,
+    if op == "read":
+        # plus one (8, cols) slab of partial sums written per tile of
+        # stream_read's default 256 rows, read back once by the final sum
+        rows, cols = a.shape
+        return n + 2 * (rows // min(256, rows)) * 8 * cols * a.dtype.itemsize
+    return {"write": n, "copy": 2 * n, "scale": 2 * n,
             "add": 3 * n, "triad": 3 * n}[op]

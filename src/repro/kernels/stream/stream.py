@@ -38,8 +38,12 @@ def _write_kernel(x_scalar_ref, o_ref):
 
 
 def _read_kernel(a_ref, o_ref):
-    # reduce to one scalar per tile: reads the stream, writes O(1)
-    o_ref[0, 0] = jnp.sum(a_ref[...])
+    # fold the tile into one (8, cols) slab of partial sums with vector adds
+    # only: reads the whole stream, writes one sublane group per tile
+    acc = a_ref[0:8, :]
+    for r in range(8, a_ref.shape[0], 8):
+        acc = acc + a_ref[r:r + 8, :]
+    o_ref[...] = acc
 
 
 def _blocks(shape, block_rows):
@@ -101,12 +105,18 @@ def stream_write(shape, x, dtype=jnp.float32, *, block_rows=256,
 
 
 def stream_read(a, *, block_rows=256, interpret=False):
+    """Per-tile sums, [rows // block_rows, 1]. The kernel's output block is
+    (8, cols), since the TPU compiler needs the last two block dims to be
+    multiples of (8, 128) or the array's own; the slabs are summed here."""
     rows, cols = a.shape
     block_rows = min(block_rows, rows)
+    if block_rows % 8:
+        raise ValueError(f"block_rows must be a multiple of 8, got {block_rows}")
     grid = (rows // block_rows,)
-    return pl.pallas_call(
+    slabs = pl.pallas_call(
         _read_kernel, grid=grid,
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 1), a.dtype),
+        out_specs=pl.BlockSpec((8, cols), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * 8, cols), a.dtype),
         interpret=interpret)(a)
+    return slabs.reshape(grid[0], 8 * cols).sum(axis=1, keepdims=True)

@@ -16,10 +16,59 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.registry import serving_caps
 from repro.obs import write_chrome_trace
 from repro.serve.engine import ContinuousEngine, Request, ServeEngine
+
+
+def serve(cfg, prompts, *, batch, max_seq, max_new, engine="continuous",
+          power_cap=None, prefill_buckets="auto", kv_block_size="auto",
+          prefix_cache=True):
+    """Serve ``prompts`` (1-D token arrays) on ``cfg``'s model.
+
+    Builds the model, draws its weights from ``key(0)``, wraps each prompt
+    in a ``Request`` of ``max_new`` tokens (audio requests get synthetic
+    encoder frames), and drains them through the ``static`` or
+    ``continuous`` engine. Returns ``(model, params, engine, reqs, stats)``.
+    """
+    caps = serving_caps(cfg)
+    model = build_model(cfg, q_block=min(64, max(len(p) for p in prompts)))
+    params, _ = model.init(jax.random.key(0))
+    rng = np.random.default_rng(0)
+    reqs = []
+    for i, prompt in enumerate(prompts):
+        # synthetic encoder frames stand in for a log-mel front-end
+        frames = (rng.standard_normal((cfg.enc_seq, cfg.d_model))
+                  .astype(np.float32) if caps.needs_frames else None)
+        reqs.append(Request(i, np.asarray(prompt, np.int32),
+                            max_new_tokens=max_new, frames=frames))
+
+    if engine == "static":
+        eng = ServeEngine(model, params, batch_size=batch, max_seq=max_seq,
+                          prefill_buckets=prefill_buckets)
+        stats = {}
+        for i in range(0, len(reqs), batch):
+            group = eng.serve(reqs[i:i + batch])
+            for k, v in group.items():
+                # compile counts are engine-lifetime cumulative, not per-call
+                if isinstance(v, (int, float)) and not k.endswith("_compiles"):
+                    stats[k] = stats.get(k, 0.0) + v
+        stats["decode_tok_per_s"] = (stats["tokens_decoded"] /
+                                     stats["decode_s"] if stats.get("decode_s")
+                                     else 0.0)
+        stats["energy_by_tag"] = dict(eng.tel.session.report().by_tag)
+        stats["prefill_compiles"] = eng.trace_stats.compiles("prefill")
+        stats["decode_compiles"] = eng.trace_stats.compiles("decode")
+    else:
+        eng = ContinuousEngine(model, params, batch_size=batch,
+                               max_seq=max_seq, power_cap_w=power_cap,
+                               prefill_buckets=prefill_buckets,
+                               kv_block_size=kv_block_size,
+                               prefix_cache=prefix_cache)
+        stats = eng.serve(reqs)
+    return model, params, eng, reqs, stats
 
 
 def main(argv=None):
@@ -89,44 +138,14 @@ def main(argv=None):
     use_prefix = (caps.prefix_cache if args.prefix_cache == "auto"
                   else args.prefix_cache == "on")
 
-    model = build_model(cfg, q_block=min(64, args.prompt_len))
-    params, _ = model.init(jax.random.key(0))
     rng = np.random.default_rng(0)
-    frames = None
-    if caps.needs_frames:
-        # synthetic encoder frames stand in for a log-mel front-end
-        frames = [rng.standard_normal((cfg.enc_seq, cfg.d_model))
-                  .astype(np.float32) for _ in range(args.requests)]
-    reqs = [Request(i, rng.integers(0, cfg.vocab_size,
-                                    args.prompt_len).astype(np.int32),
-                    max_new_tokens=args.max_new,
-                    frames=frames[i] if frames is not None else None)
-            for i in range(args.requests)]
-
-    if args.engine == "static":
-        engine = ServeEngine(model, params, batch_size=args.batch,
-                             max_seq=args.max_seq, prefill_buckets=buckets)
-        stats = {}
-        for i in range(0, len(reqs), args.batch):
-            group = engine.serve(reqs[i:i + args.batch])
-            for k, v in group.items():
-                # compile counts are engine-lifetime cumulative, not per-call
-                if isinstance(v, (int, float)) and not k.endswith("_compiles"):
-                    stats[k] = stats.get(k, 0.0) + v
-        stats["decode_tok_per_s"] = (stats["tokens_decoded"] /
-                                     stats["decode_s"] if stats.get("decode_s")
-                                     else 0.0)
-        stats["energy_by_tag"] = dict(engine.tel.session.report().by_tag)
-        stats["prefill_compiles"] = engine.trace_stats.compiles("prefill")
-        stats["decode_compiles"] = engine.trace_stats.compiles("decode")
-    else:
-        engine = ContinuousEngine(model, params, batch_size=args.batch,
-                                  max_seq=args.max_seq,
-                                  power_cap_w=args.power_cap,
-                                  prefill_buckets=buckets,
-                                  kv_block_size=kv_block,
-                                  prefix_cache=use_prefix)
-        stats = engine.serve(reqs)
+    prompts = [rng.integers(0, cfg.vocab_size, args.prompt_len)
+               for _ in range(args.requests)]
+    _, _, engine, reqs, stats = serve(
+        cfg, prompts, engine=args.engine, batch=args.batch,
+        max_seq=args.max_seq, max_new=args.max_new,
+        power_cap=args.power_cap, prefill_buckets=buckets,
+        kv_block_size=kv_block, prefix_cache=use_prefix)
 
     print(f"arch={cfg.name} engine={args.engine} "
           f"adapter={stats.get('adapter', 'static')} family={cfg.family} "
@@ -167,4 +186,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,12 +20,32 @@ from repro import configs
 from repro.configs.base import ShapeConfig
 from repro.core.tracing import TraceStats, counting_jit
 from repro.data.pipeline import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.obs import MetricsRegistry, Tracer, write_chrome_trace
 from repro.train import loop as loop_mod
 from repro.train.optimizer import OptConfig, init_opt_state
-from repro.train.step import StepConfig, TrainState, make_train_step
+from repro.train.step import (StepConfig, TrainState, make_train_step,
+                              shardings, state_specs)
+
+
+def build_trainer(cfg, mesh, opt_cfg, step_cfg, *, seq, trace_stats=None):
+    """Build ``cfg``'s model on ``mesh`` (None: one device), draw its weights
+    from ``key(0)``, and jit its train step with the state donated and, on
+    a mesh, sharded by its logical axes. Returns ``(model, state,
+    train_step)``; ``train_step(state, batch) -> (state, metrics)``."""
+    model = build_model(cfg, mesh, q_block=min(512, seq))
+    params, axes = model.init(jax.random.key(0))
+    state = TrainState(params, init_opt_state(params))
+    jit_kw = {}
+    if mesh is not None:
+        jit_kw["in_shardings"] = (
+            shardings(mesh, state_specs(mesh, params, axes)), None)
+    train_step = counting_jit(make_train_step(model, opt_cfg, step_cfg),
+                              "train_step", trace_stats,
+                              donate_argnums=(0,), **jit_kw)
+    return model, state, train_step
 
 
 def main(argv=None):
@@ -57,27 +77,14 @@ def main(argv=None):
     if args.mesh_data * args.mesh_model > 1:
         mesh = make_host_mesh(data=args.mesh_data, model=args.mesh_model)
 
-    model = build_model(cfg, mesh, q_block=min(512, args.seq))
-    params, axes = model.init(jax.random.key(0))
-    state = TrainState(params, init_opt_state(params))
-
     opt_cfg = OptConfig(lr=args.lr, warmup_steps=min(20, args.steps // 5),
                         total_steps=args.steps)
-    step_cfg = StepConfig(num_microbatches=args.micro)
-    train_step = make_train_step(model, opt_cfg, step_cfg)
     # counting_jit (not bare jax.jit): a training retrace burns the same
     # silent watts a serving retrace does — the stats land in the summary
     trace_stats = TraceStats()
-    if mesh is not None:
-        from repro.train.step import batch_specs, shardings, state_specs
-        from repro.models import token_batch_specs
-        ssh = shardings(mesh, state_specs(mesh, params, axes))
-        train_step = counting_jit(train_step, "train_step", trace_stats,
-                                  in_shardings=(ssh, None),
-                                  donate_argnums=(0,))
-    else:
-        train_step = counting_jit(train_step, "train_step", trace_stats,
-                                  donate_argnums=(0,))
+    _, state, train_step = build_trainer(
+        cfg, mesh, opt_cfg, StepConfig(num_microbatches=args.micro),
+        seq=args.seq, trace_stats=trace_stats)
 
     data = SyntheticTokens(
         DataConfig(seed=0, vocab_size=cfg.vocab_size, seq_len=args.seq,
@@ -118,4 +125,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

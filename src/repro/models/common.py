@@ -7,6 +7,7 @@ with logical-axis tuples consumed by ``repro.parallel.sharding``.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional
 
 import jax
@@ -60,7 +61,9 @@ class ParamBuilder:
     def child(self, name):
         key = None
         if not self.abstract:
-            key = jax.random.fold_in(self.key, hash(name) % (2**31))
+            # a stable digest, not hash(): str hashes are salted per process,
+            # which would give every process different seeded weights
+            key = jax.random.fold_in(self.key, zlib.crc32(name.encode()))
         sub = ParamBuilder(key, self.dtype)
         self.params[name] = sub.params
         self.axes[name] = sub.axes

@@ -63,7 +63,9 @@ def ssd_chunkwise(x, b_mat, c_mat, dt, a, state, chunk=256):
         # intra-chunk
         dm = lc[:, :, None, :] - lc[:, None, :, :]         # [B,W,W,H]
         mask = jnp.tril(jnp.ones((w, w), bool))
-        A = jnp.where(mask[None, :, :, None], jnp.exp(dm), 0.0)
+        # mask before exp: above the diagonal dm > 0 overflows to inf, and
+        # where() sends that entry the gradient 0 * inf = NaN
+        A = jnp.exp(jnp.where(mask[None, :, :, None], dm, -jnp.inf))
         cb = jnp.einsum("btn,bsn->bts", cf, bf)            # [B,W,W]
         scores = cb[..., None] * A * dtc[:, None, :, :]    # [B,W,W,H]
         y_intra = jnp.einsum("btsh,bshp->bthp", scores, xf)

@@ -104,7 +104,9 @@ def mlstm_chunkwise(q, k, v, logi, logf, state, chunk=256):
         # intra-chunk: decay matrix A[t,s] = exp(lc_t - lc_s + li_s), s<=t
         dm = lc[:, :, None, :] - lc[:, None, :, :] + li[:, None, :, :]
         mask = jnp.tril(jnp.ones((w, w), bool))
-        A = jnp.where(mask[None, :, :, None], jnp.exp(dm), 0.0)  # [B,W,W,NH]
+        # mask before exp: above the diagonal dm > 0 overflows to inf, and
+        # where() sends that entry the gradient 0 * inf = NaN
+        A = jnp.exp(jnp.where(mask[None, :, :, None], dm, -jnp.inf))
         scores = jnp.einsum("bthd,bshd->btsh", qf, kf) * A
         num_intra = jnp.einsum("btsh,bshd->bthd", scores, vf)
         den_intra = jnp.sum(scores, axis=2)               # [B,W,NH]

@@ -32,8 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.tracing import (TraceStats, _abstract_signature,  # noqa: F401
-                                counting_jit)
+from repro.core.tracing import TraceStats, counting_jit
 from repro.models.common import (copy_cache_block, gather_cache_slot,
                                  mask_cache_tail, paged_gather,
                                  paged_scatter_block, paged_scatter_slot,

@@ -22,6 +22,16 @@ def _batch(cfg, b=2, s=16, key=0):
     return batch
 
 
+def test_seeded_init_is_stable_across_processes():
+    """Sub-keys derive from a stable digest of each parameter's name, not
+    from Python's per-process salted str hash, so key(0) gives the same
+    weights in every process (parent/change comparisons rely on it)."""
+    cfg = configs.get_smoke("granite-20b")
+    params, _ = build_model(cfg).init(jax.random.key(0))
+    w_up = np.asarray(params["layers"]["mlp"]["w_up"], np.float64)
+    assert w_up.sum() == pytest.approx(-32.70617608368835, rel=1e-9)
+
+
 @pytest.mark.parametrize("arch", configs.ARCH_NAMES)
 def test_forward_shapes_and_finite(arch):
     cfg = configs.get_smoke(arch)

@@ -71,6 +71,33 @@ def test_ssd_chunkwise_equals_recurrence(seed, chunk):
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("scan", ["ssd", "mlstm"])
+def test_chunkwise_grads_finite_over_a_long_decay(scan):
+    """A 256-step chunk whose decay sums to -256: exp of the masked upper
+    triangle would overflow, and its gradient through where() would be
+    NaN (as a zamba2-1.2b train step at published widths showed)."""
+    rng = np.random.default_rng(0)
+    b, t, nh, p = 1, 256, 2, 4
+    x = jnp.asarray(rng.normal(size=(b, t, nh, p)), jnp.float32)
+    if scan == "ssd":
+        bm = jnp.asarray(rng.normal(size=(b, t, p)), jnp.float32)
+
+        def loss(x, dt):     # a = -1: per-step log decay dt * a = -1
+            y, _ = ssd_chunkwise(x, bm, bm, dt, -jnp.ones((nh,)),
+                                 jnp.zeros((b, nh, p, p)), chunk=t)
+            return jnp.sum(y)
+        d = jnp.ones((b, t, nh), jnp.float32)
+    else:
+        def loss(x, logf):
+            h, _ = mlstm_chunkwise(x, x, x, jnp.zeros_like(logf), logf,
+                                   (jnp.zeros((b, nh, p, p)),
+                                    jnp.zeros((b, nh, p))), chunk=t)
+            return jnp.sum(h)
+        d = -jnp.ones((b, t, nh), jnp.float32)
+    for g in jax.grad(loss, argnums=(0, 1))(x, d):
+        assert np.isfinite(np.asarray(g)).all()
+
+
 # ---------------------------------------------------------------------------
 # query-head padding is function-preserving
 
