@@ -3,6 +3,11 @@
 All modules are pure functions over explicit parameter pytrees. Every init
 function returns ``(params, axes)`` where ``axes`` mirrors the params tree
 with logical-axis tuples consumed by ``repro.parallel.sharding``.
+
+The layers a step's device time splits into run under ``jax.named_scope``s
+(``attention`` with ``kv_repeat`` inside it, ``mlp``, ``lm_head``,
+``kv_gather``, ``kv_scatter``): metadata only, so the XLA ops of a device
+trace name their layer in ``op_name``.
 """
 from __future__ import annotations
 
@@ -159,6 +164,7 @@ def _qkv(x, p, cfg: ModelConfig, positions, shd: Sharder, use_rope=True):
     return q, k, v
 
 
+@jax.named_scope("kv_repeat")
 def _repeat_kv(k, n_q_heads):
     """GQA: repeat KV heads to the query-head count.
 
@@ -256,6 +262,7 @@ def blocked_attention(q, k, v, q_positions, k_positions, *, causal, window,
     return out
 
 
+@jax.named_scope("attention")
 def attention(x, p, cfg: ModelConfig, shd: Sharder, *, positions,
               is_global=True, causal=True, impl="blocked", q_block=512,
               kv_cache=None, cache_pos=None, use_rope=True,
@@ -409,6 +416,7 @@ def gather_cache_slot(caches, slot, batch_axis=1):
 # paged serving steps are property-tested against.
 
 
+@jax.named_scope("kv_gather")
 def paged_gather(pool, tables):
     """Materialize logical cache views through block tables.
 
@@ -422,6 +430,7 @@ def paged_gather(pool, tables):
     return jax.tree.map(g, pool)
 
 
+@jax.named_scope("kv_scatter")
 def paged_scatter_block(pool, view, tables, pos):
     """Write back, per batch row, the single block containing ``pos``.
 
@@ -442,6 +451,7 @@ def paged_scatter_block(pool, view, tables, pos):
     return jax.tree.map(s, pool, view)
 
 
+@jax.named_scope("kv_scatter")
 def paged_scatter_slot(pool, view, table_row):
     """Write a batch-1 logical view back through one slot's block table.
 
@@ -531,6 +541,7 @@ def mlp_init(pb: ParamBuilder, d_model, d_ff, L: Optional[int] = None,
     pb.dense("w_down", pre + (d_ff, d_model), pax + (hidden_axis, "embed"), fan_in=d_ff)
 
 
+@jax.named_scope("mlp")
 def mlp(x, p, shd: Sharder, hidden_axis="act_mlp"):
     g = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))
     u = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(x.dtype))
@@ -556,6 +567,7 @@ def embed(tokens, p, dtype):
     return p["embedding"].astype(dtype)[tokens]
 
 
+@jax.named_scope("lm_head")
 def unembed(x, p, shd: Sharder):
     x = rms_norm(x, p["final_norm"])
     logits = jnp.einsum("bsd,dv->bsv", x, p["unembed"].astype(x.dtype))

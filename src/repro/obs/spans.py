@@ -15,10 +15,23 @@ Lexical spans MUST use the ``with`` form and non-lexical handles MUST be
 ended on every path — dalek-lint DLK007 (``unclosed-span``) enforces both
 statically.
 
+Every lexical span is also written into the ``jax.profiler`` trace: it
+opens a ``TraceAnnotation`` of the same name for its duration, so a
+profiler session shows the program's own phases on its host plane, on the
+same clock as the device's XLA events. A lexical span given ``step_num``
+becomes a ``StepTraceAnnotation`` (the profiler's per-step view) and
+records that number as its ``step`` attribute. Non-lexical handles are not
+mirrored: a request's life overlaps every step it waits through.
+
 Spans are cheap on purpose: beginning/ending a span is a clock read plus a
 few attribute writes under a lock that is only contended when engines share
-a tracer across threads. The serving bench gates the overhead (<5% decode
-tokens/s with spans on vs off).
+a tracer across threads. With no profiler running, a lexical span costs
+about 7 us on one Xeon core, 1 us of it the annotation; a serving engine
+opens six per decode step, against a step of 100 ms or more on a TPU.
+
+:class:`GcSpans` records the garbage collector's pauses on one thread as
+lexical ``gc`` spans (attribute ``generation``), so host stalls that no
+program phase explains still land on the timeline.
 
 Attribute conventions the exporter understands:
 
@@ -32,12 +45,16 @@ Attribute conventions the exporter understands:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanRecord", "Tracer"]
+from jax import profiler
+
+__all__ = ["Span", "SpanRecord", "Tracer", "GcSpans", "span_or_null"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,11 +79,11 @@ class Span:
     and call :meth:`end` exactly once (non-lexical)."""
 
     __slots__ = ("_tracer", "span_id", "parent_id", "name", "track",
-                 "t0", "_attrs", "_ended")
+                 "t0", "_attrs", "_ended", "_ann")
 
     def __init__(self, tracer: "Tracer", span_id: int,
                  parent_id: Optional[int], name: str, track: str,
-                 t0: float, attrs: Dict[str, object]):
+                 t0: float, attrs: Dict[str, object], ann=None):
         self._tracer = tracer
         self.span_id = span_id
         self.parent_id = parent_id
@@ -75,6 +92,7 @@ class Span:
         self.t0 = t0
         self._attrs = attrs
         self._ended = False
+        self._ann = ann                 # the profiler annotation, if lexical
 
     def set(self, key: str, value) -> "Span":
         """Attach/overwrite one attribute (chainable)."""
@@ -93,6 +111,8 @@ class Span:
         self._ended = True
         self._attrs.update(attrs)
         self._tracer._finish(self)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
 
     def __enter__(self) -> "Span":
         return self
@@ -136,7 +156,8 @@ class Tracer:
     begun while another is open on the same thread records it as parent.
     When the ring fills, the *oldest* finished spans are dropped and
     ``n_dropped`` counts them — a long-running engine keeps the most recent
-    window of history instead of growing without bound.
+    window of history instead of growing without bound. The lock is
+    reentrant: a ``gc`` span may open while this thread holds it.
     """
 
     def __init__(self, capacity: int = 65536, clock=time.perf_counter):
@@ -145,7 +166,7 @@ class Tracer:
         self.capacity = capacity
         self._clock = clock
         self._epoch = clock()
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._done: List[SpanRecord] = []
         self._next_id = 0
         self._n_dropped = 0
@@ -166,10 +187,20 @@ class Tracer:
             st = self._stacks.ids = []
         return st
 
-    def span(self, name: str, track: str = "engine", **attrs) -> Span:
+    def span(self, name: str, track: str = "engine",
+             step_num: Optional[int] = None, **attrs) -> Span:
         """Open a lexical span — always use as ``with tracer.span(...)``
-        (DLK007 flags any other shape)."""
-        return self._begin(name, track, attrs, push=True)
+        (DLK007 flags any other shape). It is mirrored into the profiler
+        trace as a ``TraceAnnotation`` named ``name``; with ``step_num`` as
+        a ``StepTraceAnnotation`` of that step, recorded as attr ``step``."""
+        if step_num is None:
+            ann = profiler.TraceAnnotation(name)
+        else:
+            ann = profiler.StepTraceAnnotation(name, step_num=step_num)
+            attrs["step"] = step_num
+        sp = self._begin(name, track, attrs, push=True, ann=ann)
+        ann.__enter__()
+        return sp
 
     def begin(self, name: str, track: str = "engine", **attrs) -> Span:
         """Open a non-lexical span handle; the caller owns ending it.
@@ -178,14 +209,15 @@ class Tracer:
         run while it is queued."""
         return self._begin(name, track, attrs, push=False)
 
-    def _begin(self, name, track, attrs, push: bool) -> Span:
+    def _begin(self, name, track, attrs, push: bool, ann=None) -> Span:
         stack = self._stack()
         parent = stack[-1] if (push and stack) else None
         with self._lock:
             sid = self._next_id
             self._next_id += 1
             self._n_started += 1
-        sp = Span(self, sid, parent, name, track, self.now(), dict(attrs))
+        sp = Span(self, sid, parent, name, track, self.now(), dict(attrs),
+                  ann)
         if push:
             stack.append(sid)
         return sp
@@ -253,6 +285,53 @@ class Tracer:
             self._done = []
             self._n_dropped = 0
             self._n_started = 0
+
+
+def span_or_null(tracer: Optional[Tracer], name: str, **attrs):
+    """``tracer.span(name, **attrs)``, or a no-op context yielding
+    ``NULL_SPAN`` when tracing is off."""
+    if tracer is None:
+        return contextlib.nullcontext(NULL_SPAN)
+    return tracer.span(name, **attrs)
+
+
+class GcSpans:
+    """The garbage collector's pauses on one thread as lexical ``gc`` spans.
+
+    A ``gc.callbacks`` hook: a collection that starts on the thread that
+    made the hook opens a ``gc`` span with the collected ``generation`` and
+    the stop callback closes it, so it nests inside whatever span the
+    collection interrupted. ``install``/``remove`` add and take away the
+    hook; ``remove`` also ends a pause left open."""
+
+    def __init__(self, tracer: Tracer):
+        self.tracer = tracer
+        self.thread = threading.get_ident()
+        self._open: Optional[Span] = None
+
+    def __call__(self, phase: str, info: dict):
+        if threading.get_ident() != self.thread:
+            return
+        if phase == "start" and self._open is None:
+            self._open = self.tracer.span("gc",
+                                          generation=info.get("generation"))
+        elif phase == "stop":
+            self.close()
+
+    def close(self):
+        """End the pause in progress, if any."""
+        if self._open is not None:
+            self._open.end()
+            self._open = None
+
+    def install(self):
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def remove(self):
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+        self.close()
 
 
 def span_tree(records: List[SpanRecord]) -> Dict[Optional[int], List[SpanRecord]]:

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import contextlib
 import time
+import weakref
 from typing import Dict, List, Optional
 
 import jax
@@ -43,7 +44,8 @@ from repro.core.energy import ServePowerModel
 from repro.core.hw import DeviceSpec, TPU_V5E
 from repro.core.scheduler import ThroughputStats
 from repro.core.tags import N_GPIO
-from repro.obs import NULL_SPAN, MetricsRegistry, TelemetryEvent, Tracer
+from repro.obs import (NULL_SPAN, GcSpans, MetricsRegistry, TelemetryEvent,
+                       Tracer, span_or_null)
 from repro.serve.queue import AdmissionController, Request, RequestQueue
 from repro.serve.slots import SlotManager
 from repro.serve.state import make_adapter, resolve_buckets
@@ -358,6 +360,13 @@ class ContinuousEngine:
         self.batch_size = batch_size
         self.max_seq = max_seq
         self.trace_stats = TraceStats()
+        # observability: registry-backed run stats + request-lifecycle spans
+        # (queued -> prefill -> decode -> finish) and per-step engine spans
+        # (step_prepare, decode_step > device_wait/telemetry, emit,
+        # admission, and the adapter's prefill_wait) carrying window refs
+        # for the energy-attributed timeline export (repro.obs.export)
+        self.metrics = MetricsRegistry()
+        self.tracer = Tracer() if tracing else None
         # the family-declared backend: paged KV (flat transformers), window
         # rings (gemma3) / contiguous fallback, or recurrent carried state.
         # "auto" arguments degrade where the family can't honor them;
@@ -367,7 +376,7 @@ class ContinuousEngine:
             prefill_buckets=prefill_buckets, kv_block_size=kv_block_size,
             prefix_cache=prefix_cache, kv_pool_blocks=kv_pool_blocks,
             greedy=greedy, trace_stats=self.trace_stats,
-            on_compile=self._on_compile)
+            on_compile=self._on_compile, tracer=self.tracer)
         self.family = model.cfg.family
         self.pm = ServePowerModel(
             _count_params(params), dev=dev,
@@ -376,12 +385,6 @@ class ContinuousEngine:
         self.admission = AdmissionController(self.pm, power_cap_w, self.stats)
         self.queue = RequestQueue()
         self.slots = SlotManager(batch_size, max_seq)
-        # observability: registry-backed run stats + request-lifecycle spans
-        # (queued -> admitted -> prefill -> decode -> finish) and per-step
-        # engine spans carrying window refs for the energy-attributed
-        # timeline export (repro.obs.export)
-        self.metrics = MetricsRegistry()
-        self.tracer = Tracer() if tracing else None
         self._req_spans: Dict[int, object] = {}   # req_id -> open span
         self.tel = (EngineTelemetry(self.pm, batch_size,
                                     metrics=self.metrics)
@@ -393,6 +396,13 @@ class ContinuousEngine:
                             "family": self.family}
         self.dvfs = self.admission.apply_dvfs(batch_size)
         self.finished: List[Request] = []
+        self.n_decode_steps = 0     # the ``step`` that step spans carry
+        if self.tracer is not None:
+            # garbage-collection pauses on this thread as ``gc`` spans, for
+            # as long as the engine lives
+            gc_spans = GcSpans(self.tracer)
+            gc_spans.install()
+            weakref.finalize(self, gc_spans.remove)
 
     # attribute aliases: the adapter owns the state, but benches/tests/
     # launchers address it through the engine
@@ -518,7 +528,15 @@ class ContinuousEngine:
         (power cap, TTL) and — when paged — page availability: a request is
         admitted only if the pool can back its worst-case footprint, else
         admission defers until active requests free pages."""
-        self._shed_stale()
+        shed0 = self.queue.n_shed
+        with span_or_null(self.tracer, "admission") as sp:
+            self._shed_stale()
+            sp.update(admitted=self._fill_slots(),
+                      shed=self.queue.n_shed - shed0)
+
+    def _fill_slots(self) -> int:
+        """The admission loop; returns how many requests it prefilled."""
+        admitted = 0
         while self.queue and self.slots.free_slots():
             if self.admission.max_slots(self.batch_size) == 0:
                 while self.queue:        # cap below even 1-slot power: shed
@@ -543,13 +561,13 @@ class ContinuousEngine:
                 self._close_req_span(req, finish_reason="length", tokens=0)
                 continue
             self._prefill_into(self.slots.free_slots()[0], req)
+            admitted += 1
+        return admitted
 
     def _prefill_into(self, slot, req: Request):
         self._close_req_span(req)        # queued span ends at admission
         psp = NULL_SPAN
         if self.tracer is not None:
-            self.tracer.instant("admitted", track=f"req{req.req_id}",
-                                req_id=req.req_id, slot=slot.index)
             psp = self.tracer.begin("prefill", track=f"req{req.req_id}",
                                     req_id=req.req_id, slot=slot.index,
                                     **self._slot_attrs)
@@ -587,8 +605,9 @@ class ContinuousEngine:
             extra = dict(self._slot_attrs)
             if cached:
                 extra["cached_tokens"] = cached
-            ev = self.tel.record("prefill", dt, tail_len, {slot.index: req},
-                                 extra=extra)
+            with span_or_null(self.tracer, "telemetry", phase="prefill"):
+                ev = self.tel.record("prefill", dt, tail_len,
+                                     {slot.index: req}, extra=extra)
         psp.update(bucket=(bucket_for(tail_len, self.buckets)
                            if self.buckets else tail_len),
                    cached_tokens=cached, computed_tokens=tail_len,
@@ -604,55 +623,65 @@ class ContinuousEngine:
         self._emit(slot, first)   # prefill samples the first token
 
     def _decode_once(self):
+        step = self.n_decode_steps
         # pre-step backend bookkeeping (paged: back every active write
         # position, COW defensively-shared blocks); slots the backend can
         # no longer cover finish "pages"
-        for s in self.adapter.begin_step(list(self.slots.active_slots())):
-            self._finish(s, "pages")
-        active = self.slots.active_slots()
+        with span_or_null(self.tracer, "step_prepare", step=step) as prep:
+            for s in self.adapter.begin_step(list(self.slots.active_slots())):
+                self._finish(s, "pages")
+            active = self.slots.active_slots()
+            if active:
+                depth = len(self.queue)
+                free, evictable = self.adapter.pool_gauges()
+            prep.update(**self.adapter.step_counts)
         if not active:
             return
-        # per-step engine span: queue depth + pool occupancy gauges ride on
-        # it, and the step's sample window is referenced for the timeline's
-        # exact joule partition
-        depth = len(self.queue)
-        free, evictable = self.adapter.pool_gauges()
+        self.n_decode_steps += 1
         self.metrics.gauge("queue_depth").set(depth)
         if self.pages is not None:
             self.metrics.gauge("kv_free_blocks").set(free)
         if self.prefix is not None:
             self.metrics.gauge("kv_evictable_blocks").set(evictable)
-        step_cm = (self.tracer.span(
-            "decode_step", track="engine", active=len(active),
-            queue_depth=depth, free_blocks=free, evictable_blocks=evictable,
-            **self._slot_attrs)
-            if self.tracer is not None else contextlib.nullcontext(NULL_SPAN))
-        with step_cm as ssp:
+        # per-step engine span (a profiler step annotation): queue depth +
+        # pool occupancy gauges ride on it, and the step's sample window is
+        # referenced for the timeline's exact joule partition
+        with span_or_null(
+                self.tracer, "decode_step", step_num=step, active=len(active),
+                queue_depth=depth, free_blocks=free,
+                evictable_blocks=evictable, **self._slot_attrs) as ssp:
             tokens = jnp.asarray(self.slots.batch_tokens())
             pos = jnp.asarray(self.slots.batch_positions())
             t0 = time.perf_counter()
             next_tok = self.adapter.decode_step(tokens, pos)
-            # dalek: allow[host-sync] the designed once-per-step [B,1] fetch (EOS/budget checks)
-            toks = np.asarray(next_tok)
+            with span_or_null(self.tracer, "device_wait", step=step):
+                # dalek: allow[host-sync] the designed once-per-step [B,1] fetch (EOS/budget checks)
+                toks = np.asarray(next_tok)
             dt = time.perf_counter() - t0
             self.metrics.histogram("decode_step_s",
                                    "fused decode step wall seconds").observe(dt)
             self.stats.observe("decode", len(active), dt)
             if self.tel:
-                ev = self.tel.record("decode", dt, len(active),
-                                     {s.index: s.req for s in active},
-                                     extra=dict(self._slot_attrs))
+                with span_or_null(self.tracer, "telemetry", step=step,
+                                  phase="decode"):
+                    ev = self.tel.record("decode", dt, len(active),
+                                         {s.index: s.req for s in active},
+                                         extra=dict(self._slot_attrs))
                 if ev is not None:
                     ssp.set("window", ev.window)
-        for s in active:
-            s.req.decode_steps += 1
-            tok = int(toks[s.index, 0])
-            self.slots.advance(s, tok)
-            self._emit(s, tok)
-            # the clamp fix: a request that filled the cache finishes here
-            # instead of silently overwriting the last KV position forever
-            if s.req is not None and self.slots.at_capacity(s):
-                self._finish(s, "capacity")
+        with span_or_null(self.tracer, "emit", step=step) as esp:
+            n_done = len(self.finished)
+            for s in active:
+                s.req.decode_steps += 1
+                tok = int(toks[s.index, 0])
+                self.slots.advance(s, tok)
+                self._emit(s, tok)
+                # the clamp fix: a request that filled the cache finishes
+                # here instead of silently overwriting the last KV position
+                # forever
+                if s.req is not None and self.slots.at_capacity(s):
+                    self._finish(s, "capacity")
+            esp.set("finished", len(self.finished) - n_done)
 
     # -- driver --------------------------------------------------------------
 

@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.models.common import (reset_cache_slot, scatter_state_slot)
 from repro.models.registry import ServingCaps, serving_caps
+from repro.obs import Tracer, span_or_null
 from repro.serve.paging import (PagePool, RadixPrefixCache,
                                 resolve_kv_block_size)
 from repro.serve.queue import Request
@@ -117,7 +118,8 @@ class CacheAdapter:
 
     def __init__(self, model, params, *, batch_size: int, max_seq: int,
                  buckets, caps: ServingCaps, trace_stats: TraceStats,
-                 on_compile=None, greedy: bool = True):
+                 on_compile=None, greedy: bool = True,
+                 tracer: Optional[Tracer] = None):
         self.model = model
         self.params = params
         self.batch_size = batch_size
@@ -127,6 +129,13 @@ class CacheAdapter:
         self.trace_stats = trace_stats
         self.on_compile = on_compile
         self.greedy = greedy
+        # the engine's tracer: each prefill's blocking first-token fetch is
+        # a ``prefill_wait`` span on it
+        self.tracer = tracer
+        # what the last ``begin_step`` did to the pool (the engine's
+        # ``step_prepare`` span carries these); zero without a pool
+        self.step_counts = {"blocks_allocated": 0, "blocks_scrubbed": 0,
+                            "cow": 0}
         self.caches = None
         # non-paged backends expose inert handles so engine property
         # aliases (`engine.pages` / `engine.prefix` / `engine.block_size`)
@@ -150,6 +159,12 @@ class CacheAdapter:
         """Pre-decode bookkeeping; returns slots the backend can no longer
         back (engine finishes them with reason "pages")."""
         return []
+
+    def _first_token(self, next_tok) -> int:
+        """Block on a prefill's sampled token (a ``prefill_wait`` span)."""
+        with span_or_null(self.tracer, "prefill_wait"):
+            # dalek: allow[host-sync] first sampled token must reach the host to emit/EOS-check
+            return int(np.asarray(next_tok)[0, 0])
 
     def decode_step(self, tokens, pos):
         """One fused decode for the whole batch; returns the [B, 1] device
@@ -232,6 +247,7 @@ class PagedKVAdapter(CacheAdapter):
         pending = self.pages.drain_pending_zero()
         if not pending:
             return
+        self.step_counts["blocks_scrubbed"] += len(pending)
         width = self.n_slot_blocks
         for i in range(0, len(pending), width):
             chunk = pending[i:i + width]
@@ -248,6 +264,8 @@ class PagedKVAdapter(CacheAdapter):
             if self.prefix.evict(1):
                 self._flush_freed()
                 blk = self.pages.alloc()
+        if blk is not None:
+            self.step_counts["blocks_allocated"] += 1
         return blk
 
     # -- admission ----------------------------------------------------------
@@ -302,8 +320,7 @@ class PagedKVAdapter(CacheAdapter):
             next_tok, _, self.caches = self._prefill_slot(
                 self.params, jnp.asarray(tail[None, :]), jnp.int32(start),
                 table_row, self.caches)
-        # dalek: allow[host-sync] first sampled token must reach the host to emit/EOS-check
-        first = int(np.asarray(next_tok)[0, 0])
+        first = self._first_token(next_tok)
         if self.prefix is not None:
             self.prefix.insert(prompt, self.pages.table_row(slot_index))
         return PrefillOutcome(first, cached_tokens=start,
@@ -313,11 +330,13 @@ class PagedKVAdapter(CacheAdapter):
         """Back every active slot's write position before the fused step:
         fresh block on a boundary, COW if (defensively) shared, report the
         slot for a "pages" finish when the pool is dry."""
+        self.step_counts = dict.fromkeys(self.step_counts, 0)
         doomed = []
         for s in active_slots:
             state, src, dst = self.pages.ensure_writable(
                 s.index, s.pos, self._alloc_block)
             if state == "cow":
+                self.step_counts["cow"] += 1
                 self.caches = self._copy_block(
                     self.caches, jnp.int32(src), jnp.int32(dst))
             elif state == "oom":
@@ -401,8 +420,7 @@ class WindowRingAdapter(CacheAdapter):
             next_tok, _, self.caches = self._prefill_slot(
                 self.params, jnp.asarray(prompt[None, :]),
                 jnp.int32(slot_index), self.caches)
-        # dalek: allow[host-sync] first sampled token must reach the host to emit/EOS-check
-        first = int(np.asarray(next_tok)[0, 0])
+        first = self._first_token(next_tok)
         return PrefillOutcome(first, computed_tokens=len(prompt))
 
     def decode_step(self, tokens, pos):
@@ -489,8 +507,7 @@ class RecurrentStateAdapter(CacheAdapter):
         # AND resets the row in one write (every leaf row is overwritten)
         self.caches = self._scatter(self.caches, state,
                                     jnp.int32(slot_index))
-        # dalek: allow[host-sync] first sampled token must reach the host to emit/EOS-check
-        first = int(np.asarray(next_tok)[0, 0])
+        first = self._first_token(next_tok)
         return PrefillOutcome(first, computed_tokens=len(prompt))
 
     def decode_step(self, tokens, pos):
@@ -512,7 +529,8 @@ def make_adapter(model, params, *, batch_size: int, max_seq: int,
                  prefill_buckets="auto", kv_block_size="auto",
                  prefix_cache: bool = True,
                  kv_pool_blocks: Optional[int] = None, greedy: bool = True,
-                 trace_stats: Optional[TraceStats] = None, on_compile=None):
+                 trace_stats: Optional[TraceStats] = None, on_compile=None,
+                 tracer: Optional[Tracer] = None):
     """Select and build the backend for ``model``'s declared capabilities.
 
     ``"auto"`` arguments degrade silently where the family can't honor them
@@ -524,7 +542,7 @@ def make_adapter(model, params, *, batch_size: int, max_seq: int,
     trace_stats = trace_stats if trace_stats is not None else TraceStats()
     common = dict(batch_size=batch_size, max_seq=max_seq, buckets=buckets,
                   caps=caps, trace_stats=trace_stats, on_compile=on_compile,
-                  greedy=greedy)
+                  greedy=greedy, tracer=tracer)
     block_size = resolve_kv_block_size(kv_block_size, max_seq, caps.paged_kv)
     if caps.kind == "recurrent":
         return RecurrentStateAdapter(model, params, **common)

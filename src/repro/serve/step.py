@@ -7,7 +7,8 @@ model's ``cache_axes()`` logical axes; for batch=1 long-context decode the
 syncs once per step for the whole batch (one [B,1] token fetch) instead of
 once per slot; ``pos`` may be a [B] vector for continuous batching.
 ``make_slot_prefill`` prefills a single request into one batch row of the
-shared cache while the other rows keep their in-flight state.
+shared cache while the other rows keep their in-flight state. Sampling runs
+under the ``sample`` named scope (see ``models.common`` for the others).
 
 Prompt-length bucketing: an exact-length prefill retraces one executable
 per distinct prompt length, so production-shaped traffic (every prompt a
@@ -97,6 +98,14 @@ def pad_to_bucket(prompt: np.ndarray, buckets: Sequence[int],
 # step builders
 
 
+@jax.named_scope("sample")
+def _sample(logits, greedy=True, key=None):
+    """The next token from ``logits``: argmax, or a draw with ``key``."""
+    if greedy or key is None:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(key, logits).astype(jnp.int32)
+
+
 def make_prefill_step(model, bucketed: bool = False):
     """Whole-batch prefill. ``bucketed=True`` adds a traced ``true_len``
     argument: the batch is right-padded to a bucket edge, logits come from
@@ -119,11 +128,7 @@ def make_decode_step(model, greedy=True):
     """Fused decode + in-jit sampling. ``pos``: scalar or [B] int32."""
     def decode_step(params, tokens, pos, caches, key=None):
         logits, caches = model.decode_step(params, tokens, pos, caches)
-        if greedy or key is None:
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            next_tok = jax.random.categorical(key, logits).astype(jnp.int32)
-        return next_tok, logits, caches
+        return _sample(logits, greedy, key), logits, caches
     return decode_step
 
 
@@ -140,7 +145,7 @@ def make_slot_prefill(model, bucketed: bool = False):
         def slot_prefill(params, tokens, slot, caches):
             sub = gather_cache_slot(caches, slot)
             logits, sub = model.prefill(params, {"tokens": tokens}, sub)
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            next_tok = _sample(logits)
             return next_tok, logits, scatter_cache_slot(caches, sub, slot)
         return slot_prefill
 
@@ -149,7 +154,7 @@ def make_slot_prefill(model, bucketed: bool = False):
         logits, sub = model.prefill(params, {"tokens": tokens}, sub,
                                     true_len=true_len)
         sub = mask_cache_tail(sub, true_len)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tok = _sample(logits)
         return next_tok, logits, scatter_cache_slot(caches, sub, slot)
     return bucketed_slot_prefill
 
@@ -166,10 +171,7 @@ def make_paged_decode_step(model, greedy=True):
     def paged_decode_step(params, tokens, pos, tables, pool, key=None):
         view = paged_gather(pool, tables)
         logits, view = model.decode_step(params, tokens, pos, view)
-        if greedy or key is None:
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            next_tok = jax.random.categorical(key, logits).astype(jnp.int32)
+        next_tok = _sample(logits, greedy, key)
         pool = paged_scatter_block(pool, view, tables, pos)
         return next_tok, logits, pool
     return paged_decode_step
@@ -193,7 +195,7 @@ def make_paged_slot_prefill(model, bucketed: bool = False):
             logits, sub = model.prefill(params, {"tokens": tokens}, sub,
                                         start_pos=start_pos)
             sub = mask_cache_tail(sub, start_pos + tokens.shape[1])
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            next_tok = _sample(logits)
             return next_tok, logits, paged_scatter_slot(pool, sub, table_row)
         return paged_slot_prefill
 
@@ -203,7 +205,7 @@ def make_paged_slot_prefill(model, bucketed: bool = False):
         logits, sub = model.prefill(params, {"tokens": tokens}, sub,
                                     true_len=true_len, start_pos=start_pos)
         sub = mask_cache_tail(sub, start_pos + true_len)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tok = _sample(logits)
         return next_tok, logits, paged_scatter_slot(pool, sub, table_row)
     return paged_bucketed_slot_prefill
 
@@ -246,7 +248,7 @@ def make_recurrent_chunk_prefill(model):
             batch["frames"] = frames
         logits, state = model.prefill(params, batch, state,
                                       start_pos=start_pos)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tok = _sample(logits)
         return next_tok, logits, state
     return chunk_prefill
 

@@ -1,15 +1,18 @@
 """Observability layer tests: typed events, tracer, metrics registry, and
 the energy-attributed Perfetto export (round-trip + sum-to-total)."""
+import gc
 import json
 import threading
 
 import numpy as np
 import pytest
 
-from repro.obs import (MetricsRegistry, SpanRecord, TelemetryEvent, Tracer,
-                       chrome_trace, coerce_event, events_from_meta,
-                       events_to_meta, parse_chrome_trace, span_tree,
-                       validate_chrome_trace, window_of, write_chrome_trace)
+from profiling import profiled_host_events
+from repro.obs import (NULL_SPAN, GcSpans, MetricsRegistry, SpanRecord,
+                       TelemetryEvent, Tracer, chrome_trace, coerce_event,
+                       events_from_meta, events_to_meta, parse_chrome_trace,
+                       span_or_null, span_tree, validate_chrome_trace,
+                       window_of, write_chrome_trace)
 
 # -- typed telemetry events ----------------------------------------------------
 
@@ -129,6 +132,69 @@ def test_tracer_thread_safety():
 def test_tracer_capacity_validation():
     with pytest.raises(ValueError):
         Tracer(capacity=0)
+
+
+def test_lexical_spans_land_in_the_profiler_trace(tmp_path):
+    tr = Tracer()
+
+    def work():
+        h = tr.begin("queued", track="req0")
+        with tr.span("outer"):
+            with tr.span("inner", batch=2):
+                pass
+        with tr.span("decode_step", step_num=7, active=3):
+            pass
+        h.end()
+        tr.instant("finish", track="req0")
+
+    host = profiled_host_events(work, tmp_path)
+    names = [e[0] for e in host]
+    for name in ("outer", "inner", "decode_step"):
+        assert names.count(name) == 1, name
+    # non-lexical handles and instants stay out of the profiler's trace
+    assert "queued" not in names and "finish" not in names
+    step = next(e for e in host if e[0] == "decode_step")
+    assert step[3].get("step_num") == 7          # a step annotation
+    outer = next(e for e in host if e[0] == "outer")
+    inner = next(e for e in host if e[0] == "inner")
+    assert outer[1] <= inner[1] <= inner[2] <= outer[2]
+    # the step number is recorded on the span too
+    rec = {r.name: r for r in tr.spans()}
+    assert rec["decode_step"].attrs == {"step": 7, "active": 3}
+    assert rec["inner"].attrs == {"batch": 2}
+
+
+def test_gc_spans_record_pauses_on_their_thread():
+    tr = Tracer()
+    hook = GcSpans(tr)
+    hook.install()
+    hook.install()                                 # idempotent
+    try:
+        with tr.span("step"):
+            gc.collect()
+        other = threading.Thread(target=gc.collect)
+        other.start()
+        other.join()
+    finally:
+        hook.remove()
+    gc.collect()                                   # removed: not recorded
+    assert hook not in gc.callbacks
+    recs = tr.spans()
+    pauses = [r for r in recs if r.name == "gc"]
+    assert len(pauses) == 1                        # not the other thread's
+    step = next(r for r in recs if r.name == "step")
+    assert pauses[0].parent_id == step.span_id
+    assert pauses[0].attrs == {"generation": 2}
+    assert step.t0 <= pauses[0].t0 <= pauses[0].t1 <= step.t1
+
+
+def test_span_or_null():
+    with span_or_null(None, "x", a=1) as sp:
+        assert sp is NULL_SPAN
+    tr = Tracer()
+    with span_or_null(tr, "x", a=1) as sp:
+        sp.set("b", 2)
+    assert tr.spans()[0].attrs == {"a": 1, "b": 2}
 
 
 # -- metrics registry ----------------------------------------------------------
