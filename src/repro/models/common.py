@@ -7,7 +7,8 @@ with logical-axis tuples consumed by ``repro.parallel.sharding``.
 The layers a step's device time splits into run under ``jax.named_scope``s
 (``attention`` with ``kv_repeat`` inside it, ``mlp``, ``lm_head``,
 ``kv_gather``, ``kv_scatter``): metadata only, so the XLA ops of a device
-trace name their layer in ``op_name``.
+trace name their layer in ``op_name``. On a TPU the paged decode step's
+attention is the ``paged_attention`` kernel, with no gather or repeat.
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention.paged_attention import paged_attention
 from repro.parallel.sharding import Sharder
 
 # ---------------------------------------------------------------------------
@@ -431,27 +433,6 @@ def paged_gather(pool, tables):
 
 
 @jax.named_scope("kv_scatter")
-def paged_scatter_block(pool, view, tables, pos):
-    """Write back, per batch row, the single block containing ``pos``.
-
-    Decode mutates exactly one position per slot, so only the touched block
-    needs to return to the pool. ``pos``: [B] int32 per-slot positions.
-    Free slots point at the reserved null block; their duplicate scatter
-    indices collide there harmlessly (the null block is never read).
-    """
-    b = tables.shape[0]
-    bidx = jnp.arange(b)
-
-    def s(c, v):
-        blk_size = c.shape[2]
-        blk = pos // blk_size
-        vr = v.reshape(v.shape[0], b, -1, blk_size, *v.shape[3:])
-        touched = vr[:, bidx, blk]               # [L, B, block, ...]
-        return c.at[:, tables[bidx, blk]].set(touched)
-    return jax.tree.map(s, pool, view)
-
-
-@jax.named_scope("kv_scatter")
 def paged_scatter_slot(pool, view, table_row):
     """Write a batch-1 logical view back through one slot's block table.
 
@@ -466,6 +447,94 @@ def paged_scatter_slot(pool, view, table_row):
         vr = v.reshape(v.shape[0], -1, blk_size, *v.shape[3:])
         return c.at[:, table_row].set(vr)
     return jax.tree.map(s, pool, view)
+
+
+# ---------------------------------------------------------------------------
+# paged decode: one token per slot, written to and read from the pool in place
+#
+# Decode never builds the contiguous view. Each layer writes every slot's new
+# K/V into the pool block holding its position, then attends over the slot's
+# live blocks [0, pos] through its table. On a TPU the attention is the
+# Pallas kernel (``kernels.paged_attention``), which DMAs live blocks out of
+# the stacked pool; elsewhere it is the reference: that layer's view gathered
+# through the table, then the contiguous decode path's own repeat, mask and
+# scores, so paged and contiguous serving stay bit-identical on the CPU.
+
+
+@jax.named_scope("kv_scatter")
+def paged_write(pool, layer, tables, pos, k, v):
+    """Write each slot's new ``k``/``v`` ([B, kvh, dh]) at position ``pos``
+    ([B]) of layer ``layer`` of the stacked pool ([L, P, block, kvh, dh]
+    leaves), through the slot's block table ([B, NB]). Free slots sit on
+    the null block at position 0; their colliding writes land there."""
+    kp, vp = pool
+    blk_size = kp.shape[2]
+    blk = tables[jnp.arange(tables.shape[0]), pos // blk_size]
+    off = pos % blk_size
+    return (kp.at[layer, blk, off].set(k.astype(kp.dtype)),
+            vp.at[layer, blk, off].set(v.astype(vp.dtype)))
+
+
+def _paged_attention_reference(q, kp, vp, layer, tables, pos, cfg, shd,
+                               scores_f32):
+    """q: [B, 1, H, dh] -> [B, 1, H, dh], as ``attention`` decodes a
+    contiguous cache, over the layer's view gathered through ``tables``."""
+    b = tables.shape[0]
+    with jax.named_scope("kv_gather"):
+        k, v = (jnp.take(c[layer], tables, axis=0).reshape(b, -1, *c.shape[3:])
+                for c in (kp, vp))
+    k = shd(_repeat_kv(k.astype(q.dtype), cfg.q_heads),
+            "batch", None, "act_heads", None)
+    v = shd(_repeat_kv(v.astype(q.dtype), cfg.q_heads),
+            "batch", None, "act_heads", None)
+    k_positions = jnp.arange(k.shape[1])
+    m = _mask(pos[:, None], k_positions, causal=True, window=None,
+              is_global=True)
+    m = m & (k_positions[None, :] < pos[:, None] + 1)[:, None, :]
+    return attention_scores(q, k, v, m, scores_f32)
+
+
+def paged_decode_impl(cfg: ModelConfig, platform: str) -> str:
+    """Which paged decode attention a step lowered for ``platform`` runs:
+    the kernel on a TPU where each head fills whole 128-lane rows (every
+    published width; the kernel's DMAs slice the pool along the head dim),
+    else the reference."""
+    if platform == "tpu" and cfg.head_dim % 128 == 0:
+        return "pallas"
+    return "reference"
+
+
+def paged_decode_attention(x, p, cfg: ModelConfig, shd: Sharder, *, pos,
+                           layer, tables, pool, scores_f32=True):
+    """One decode token per slot through one layer's attention on the pool.
+
+    x: [B, 1, D]; pos: [B] int32 (each slot's write position; it attends
+    over [0, pos]); layer: int32 scalar; tables: [B, NB] block ids; pool:
+    (k, v) stacked [L, P, block, kvh, dh]. Returns (out [B, 1, D], pool)
+    with the slot's new K/V written in place."""
+    b = x.shape[0]
+    with jax.named_scope("attention"):
+        q, k, v = _qkv(x, p, cfg, pos[:, None], shd)
+    pool = paged_write(pool, layer, tables, pos, k[:, 0], v[:, 0])
+    kp, vp = pool
+
+    def pallas(q, kp, vp, layer, tables, pos):
+        o = paged_attention(q[:, 0], kp, vp, layer, tables, pos + 1)
+        return o[:, None]
+
+    def reference(q, kp, vp, layer, tables, pos):
+        return _paged_attention_reference(q, kp, vp, layer, tables, pos,
+                                          cfg, shd, scores_f32)
+
+    with jax.named_scope("attention"):
+        fits = paged_decode_impl(cfg, "tpu") == "pallas"
+        o = lax.platform_dependent(q, kp, vp, layer, tables, pos,
+                                   tpu=pallas if fits else reference,
+                                   default=reference)
+        o = o.reshape(b, 1, cfg.q_heads, cfg.head_dim)
+        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
+        out = shd(out, "batch", "seq", "act_embed")
+    return out, pool
 
 
 def reset_cache_blocks(pool, blocks):

@@ -379,3 +379,54 @@ class DecoderLM:
                                        caches=caches, cache_pos=pos)
         logits = common.unembed(x, params, self.shd)
         return logits, caches
+
+    # -- paged serving -------------------------------------------------------
+
+    def _paged_block(self, x, p, *, layer, pos, tables, pool, is_moe):
+        cfg, shd = self.cfg, self.shd
+        h, pool = common.paged_decode_attention(
+            common.rms_norm(x, p["norm1"]), p["attn"], cfg, shd, pos=pos,
+            layer=layer, tables=tables, pool=pool, scores_f32=self.scores_f32)
+        x = x + h
+        y = common.rms_norm(x, p["norm2"])
+        if is_moe:
+            ff, _ = moe_mod.moe_apply(y, p["moe"], cfg, shd,
+                                      impl=self.moe_impl)
+        else:
+            ff = common.mlp(y, p["mlp"], shd)
+        return x + ff, pool
+
+    def paged_decode_step(self, params, token, pos, tables, pool):
+        """One decode step straight on a paged KV pool.
+
+        token: [B, 1] int32; pos: [B] int32 per-slot positions; tables:
+        [B, NB] block ids; pool: (k, v) stacked [L, P, block, kvh, dh]. The
+        layers carry the pool itself: each writes every slot's new K/V into
+        its block in place and attends over the slot's live blocks
+        (``common.paged_decode_attention``). Returns (logits, pool)."""
+        cfg = self.cfg
+        if self.shd.mesh is not None and self.shd.mesh.size > 1:
+            raise NotImplementedError(
+                "paged decode runs on one device: the pool is not sharded "
+                "over KV heads")
+        assert cfg.local_global_period <= 0 and cfg.sliding_window <= 0, (
+            "paged decode attends every live position; windowed layers "
+            "serve from ring caches")
+        x = common.embed(token, params, jnp.dtype(cfg.dtype))
+        x = self.shd(x, "batch", "seq", "act_embed")
+        pos = pos.astype(jnp.int32)
+        step = partial(self._paged_block, pos=pos, tables=tables)
+        for i in range(cfg.first_k_dense):
+            x, pool = step(x, params["dense_prefix"][f"layer_{i}"],
+                           layer=jnp.int32(i), pool=pool, is_moe=False)
+
+        def body(carry, p):
+            xc, pool, li = carry
+            xc, pool = step(xc, p, layer=li, pool=pool, is_moe=cfg.is_moe)
+            return (xc, pool, li + 1), None
+
+        (x, pool, _), _ = lax.scan(
+            body, (x, tuple(pool), jnp.int32(cfg.first_k_dense)),
+            params["layers"])
+        logits = common.unembed(x, params, self.shd)
+        return logits, pool

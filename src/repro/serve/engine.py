@@ -651,7 +651,11 @@ class ContinuousEngine:
                 queue_depth=depth, free_blocks=free,
                 evictable_blocks=evictable, **self._slot_attrs) as ssp:
             tokens = jnp.asarray(self.slots.batch_tokens())
-            pos = jnp.asarray(self.slots.batch_positions())
+            positions = self.slots.batch_positions()
+            kv_tokens = self.adapter.kv_tokens(positions)
+            if kv_tokens is not None:
+                ssp.set("kv_tokens", kv_tokens)
+            pos = jnp.asarray(positions)
             t0 = time.perf_counter()
             next_tok = self.adapter.decode_step(tokens, pos)
             with span_or_null(self.tracer, "device_wait", step=step):

@@ -4,11 +4,13 @@ The fixed per-slot KV tensors bound concurrency by worst-case sequence
 length: a slot owns ``max_seq`` cache positions whether its request uses 9
 or 9000. Paging replaces that with fixed-size KV *blocks* (``block_size``
 positions each) drawn from one shared pool; a slot's cache is a *block
-table* — an ordered list of block ids — and the jitted steps gather the
-slot's logical view through that indirection (``models.common.paged_gather``/
-``paged_scatter_*``). Blocks are reference-counted: a block shared by N
-owners is stored once, and copy-on-write (``ensure_writable``) guarantees a
-writer never mutates a block another owner can still read.
+table* — an ordered list of block ids. Prefill gathers the slot's logical
+view through that indirection (``models.common.paged_gather``/
+``paged_scatter_slot``); decode writes and reads the slot's blocks in
+place (``models.common.paged_decode_attention``). Blocks are
+reference-counted: a block shared by N owners is stored once, and
+copy-on-write (``ensure_writable``) guarantees a writer never mutates a
+block another owner can still read.
 
 On top of the allocator sits a radix/trie prefix cache keyed on token
 content at block granularity: production traffic is dominated by shared
@@ -23,7 +25,8 @@ Invariants the engine relies on:
 
 - block 0 is the reserved *null* block: free slots and unallocated table
   entries point at it, so gather/scatter indices are always in range and
-  duplicate scatters land harmlessly in a block nothing ever reads.
+  duplicate scatters land harmlessly in a block no request ever reads
+  (a free slot's decode reads its position 0, and its output is dropped).
 - only FULL blocks are ever shared (trie matching is block-granular), so a
   slot's write position — decode append or prefill tail — always lands in a
   block it owns exclusively; ``ensure_writable`` is a defensive backstop,

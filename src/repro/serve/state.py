@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.common import (reset_cache_slot, scatter_state_slot)
+from repro.models.common import (paged_decode_impl, reset_cache_slot,
+                                 scatter_state_slot)
 from repro.models.registry import ServingCaps, serving_caps
 from repro.obs import Tracer, span_or_null
 from repro.serve.paging import (PagePool, RadixPrefixCache,
@@ -196,6 +197,11 @@ class CacheAdapter:
     def run_stats(self) -> Dict:
         return {"kv_block_size": self.block_size}
 
+    def kv_tokens(self, positions: np.ndarray) -> Optional[int]:
+        """Cache positions one decode step at ``positions`` attends over,
+        summed over the batch; None where the backend does not count them."""
+        return None
+
     def reset_metrics(self):
         """Benchmark warmup reset: drop cached/shared state *statistics*
         (jit caches and buffers survive — freed slots are always
@@ -222,9 +228,11 @@ class PagedKVAdapter(CacheAdapter):
                               block_size)
         self.prefix = (RadixPrefixCache(block_size, self.pages)
                        if prefix_cache else None)
+        # the pool is donated: decode writes it in place, and every caller
+        # replaces its handle with the step's output
         self._decode = counting_jit(
             make_paged_decode_step(model, self.greedy), "decode",
-            self.trace_stats, on_compile=self.on_compile)
+            self.trace_stats, on_compile=self.on_compile, donate_argnums=(4,))
         self._prefill_slot = counting_jit(
             make_paged_slot_prefill(model, bucketed=bool(self.buckets)),
             "prefill", self.trace_stats, on_compile=self.on_compile)
@@ -364,10 +372,19 @@ class PagedKVAdapter(CacheAdapter):
                      if self.prefix is not None else -1)
         return free, evictable
 
+    def kv_tokens(self, positions: np.ndarray) -> Optional[int]:
+        # every slot, free ones included, reads [0, pos]
+        return int(positions.sum()) + len(positions)
+
     def run_stats(self) -> Dict:
         pg = self.pages.stats.as_dict()
         pg["free_blocks"] = self.pages.free_blocks()
-        out = {"kv_block_size": self.block_size, "kv_pages": pg}
+        # the platform the pool lives on is the one decode was lowered for
+        platform = (next(iter(self.caches[0].devices())).platform
+                    if self.caches is not None else jax.default_backend())
+        out = {"kv_block_size": self.block_size, "kv_pages": pg,
+               "decode_attention": paged_decode_impl(self.model.cfg,
+                                                     platform)}
         if self.prefix is not None:
             out["prefix_cache"] = self.prefix.stats.as_dict()
         return out

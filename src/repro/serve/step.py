@@ -36,8 +36,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core.tracing import TraceStats, counting_jit
 from repro.models.common import (copy_cache_block, gather_cache_slot,
                                  mask_cache_tail, paged_gather,
-                                 paged_scatter_block, paged_scatter_slot,
-                                 reset_cache_blocks, scatter_cache_slot)
+                                 paged_scatter_slot, reset_cache_blocks,
+                                 scatter_cache_slot)
 from repro.parallel.sharding import spec_for
 
 # compile accounting (``TraceStats``/``counting_jit``) lives in
@@ -160,20 +160,19 @@ def make_slot_prefill(model, bucketed: bool = False):
 
 
 def make_paged_decode_step(model, greedy=True):
-    """Fused decode through block-table indirection.
+    """Fused decode straight on the block pool.
 
-    The pool ([L, P, block, kvh, dh] leaves) is gathered into per-slot
-    contiguous views via ``tables`` ([B, NB] block ids), the unmodified
-    model decode runs on the view, and only each slot's touched block is
-    scattered back. Table *values* are traced, so remaps (prefix sharing,
-    COW, lazy growth) never retrace — the decode executable count stays 1.
+    ``model.paged_decode_step`` writes each slot's new K/V into the pool
+    block at its position and attends over the slot's live blocks through
+    ``tables`` ([B, NB] block ids): no contiguous view is built. The pool
+    is updated in place when the caller donates it. Table *values* are
+    traced, so remaps (prefix sharing, COW, lazy growth) never retrace —
+    the decode executable count stays 1.
     """
     def paged_decode_step(params, tokens, pos, tables, pool, key=None):
-        view = paged_gather(pool, tables)
-        logits, view = model.decode_step(params, tokens, pos, view)
-        next_tok = _sample(logits, greedy, key)
-        pool = paged_scatter_block(pool, view, tables, pos)
-        return next_tok, logits, pool
+        logits, pool = model.paged_decode_step(params, tokens, pos, tables,
+                                               pool)
+        return _sample(logits, greedy, key), logits, pool
     return paged_decode_step
 
 

@@ -12,6 +12,7 @@ from repro.kernels.stream import ops as stream_ops, ref as stream_ref
 from repro.kernels.stream import stream as stream_k
 from repro.kernels.dpa_matmul import ops as dpa_ops, ref as dpa_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
+from repro.kernels.paged_attention import ops as pa_ops, ref as pa_ref
 
 I = dict(interpret=True)
 
@@ -152,3 +153,104 @@ def test_flash_block_shape_invariance(seed, bq, bkv):
                            interpret=True)
     want = fa_ref.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention
+#
+# One tolerance for every comparison below, from bfloat16 rounding of the
+# output. Each output is bfloat16 of magnitude under 4 (asserted), where one
+# ulp is 2^-6. The sides round at different points: the kernel keeps scores
+# in float32 and rounds its output once; the model's reference also rounds
+# the score product and the probabilities to bfloat16. That moves an output
+# by about one ulp (measured: at most 2^-6), so two ulps are allowed:
+# PAGED_TOL = 2^-5. A wrong mask, head or block moves an output by O(1).
+
+PAGED_TOL = 2 * 2.0 ** -6
+
+
+def _paged_case(kvh, ratio, block=8, n_table=4, n_layers=2, dh=128, seed=0):
+    """Four live slots at lengths 1, block, block + 1 and max_seq, each on
+    a shuffled block table, and a free slot on the null block at length 1.
+    Returns the pools, the contiguous per-slot caches they hold, and the
+    query, layer, tables and lengths."""
+    rng = np.random.default_rng(seed)
+    h, max_seq = kvh * ratio, block * n_table
+    lengths = np.array([1, block, block + 1, max_seq, 1], np.int32)
+    n_live = len(lengths) - 1
+    n_pool = n_live * n_table + 1
+    tables = np.zeros((len(lengths), n_table), np.int32)   # free: null block
+    tables[:n_live] = rng.permutation(np.arange(1, n_pool)).reshape(
+        n_live, n_table)
+    shape = (n_layers, n_pool, block, kvh, dh)
+    pools = [rng.normal(size=shape).astype(np.float32) for _ in "kv"]
+    layer = n_layers - 1
+    conts = [p[layer][tables].reshape(len(lengths), max_seq, kvh, dh)
+             for p in pools]
+    q = rng.normal(size=(len(lengths), h, dh))
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)
+    return (bf(q), bf(pools[0]), bf(pools[1]), [bf(c) for c in conts],
+            jnp.int32(layer), jnp.asarray(tables), jnp.asarray(lengths))
+
+
+def _paged_kernel(q, kp, vp, layer, tables, lengths):
+    from jax.experimental.pallas import tpu as pltpu
+    # uninitialised VMEM reads as NaN, so a buffer row the kernel reads but
+    # never fetched shows
+    return pa_ops.attention(q, kp, vp, layer, tables, lengths,
+                            blocks_per_chunk=2,
+                            interpret=pltpu.InterpretParams())
+
+
+def _contiguous_decode(q, k, v, lengths, cfg):
+    """The contiguous decode path's masked attention over [B, T] caches."""
+    from repro.models import common
+    t = jnp.arange(k.shape[1])
+    pos = lengths[:, None] - 1
+    m = common._mask(pos, t, causal=True, window=None, is_global=True)
+    m = m & (t[None, :] < lengths[:, None])[:, None, :]
+    k = common._repeat_kv(k, cfg.q_heads)
+    v = common._repeat_kv(v, cfg.q_heads)
+    return common.attention_scores(q[:, None], k, v, m)[:, 0]
+
+
+@pytest.mark.parametrize("kvh,ratio", [(4, 1), (2, 7), (2, 8), (1, 48)])
+def test_paged_attention_matches_reference_and_contiguous(kvh, ratio):
+    from repro import configs
+    from repro.models import common
+    from repro.parallel.sharding import Sharder
+    q, kp, vp, (kc, vc), layer, tables, lengths = _paged_case(kvh, ratio)
+    cfg = configs.get_smoke("qwen3-32b").replace(
+        num_heads=kvh * ratio, num_kv_heads=kvh, head_dim=q.shape[-1])
+    got = np.asarray(_paged_kernel(q, kp, vp, layer, tables, lengths),
+                     np.float32)
+    assert np.isfinite(got).all()            # the free slot too
+    assert np.abs(got).max() < 4
+    reference = common._paged_attention_reference(
+        q[:, None], kp, vp, layer, tables, lengths - 1, cfg, Sharder(None),
+        scores_f32=True)[:, 0]
+    contiguous = _contiguous_decode(q, kc, vc, lengths, cfg)
+    # the model's reference is the contiguous path's arithmetic, bit for bit
+    np.testing.assert_array_equal(np.asarray(reference, np.float32),
+                                  np.asarray(contiguous, np.float32))
+    for want in (reference, contiguous,
+                 pa_ref.paged_attention(q, kp, vp, layer, tables, lengths)):
+        np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                                   atol=PAGED_TOL, rtol=0)
+
+
+def test_paged_attention_reads_only_live_blocks():
+    """Blocks no slot's length reaches are never read: filled with NaN,
+    they leave every output exactly as it was."""
+    q, kp, vp, _, layer, tables, lengths = _paged_case(2, 8, seed=1)
+    want = _paged_kernel(q, kp, vp, layer, tables, lengths)
+    block = kp.shape[2]
+    live = np.zeros(kp.shape[1], bool)
+    for row, n in zip(np.asarray(tables), np.asarray(lengths)):
+        live[row[:-(-n // block)]] = True
+    dead = jnp.asarray(~live)[None, :, None, None, None]
+    nan = jnp.asarray(np.nan, kp.dtype)
+    got = _paged_kernel(q, jnp.where(dead, nan, kp), jnp.where(dead, nan, vp),
+                        layer, tables, lengths)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

@@ -292,6 +292,22 @@ if HAVE_HYPOTHESIS:
                                         n_decode=5, seed=seed)
 
 
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "internvl2-76b"])
+def test_paged_matches_contiguous_other_families(arch):
+    """The paged decode stack of the other paged families (an unrolled
+    dense first layer before MoE layers; a VLM backbone) is bit-exact
+    against their contiguous decode too."""
+    cfg = configs.get_smoke(arch)
+    model = build_model(cfg, q_block=8)
+    params, _ = model.init(jax.random.key(0))
+    steps = (jax.jit(make_slot_prefill(model)),
+             jax.jit(make_paged_slot_prefill(model)),
+             jax.jit(make_decode_step(model)),
+             jax.jit(make_paged_decode_step(model)))
+    _check_paged_matches_contiguous(cfg, model, params, steps, 8,
+                                    plens=(5, 13), n_decode=4, seed=3)
+
+
 def test_paged_matches_contiguous_seeded(dense, paged_steps):
     """Deterministic sweep covering block-boundary edges (runs even without
     hypothesis): lengths on, just under, and just over block edges."""
@@ -566,3 +582,60 @@ def test_shed_estimate_prices_net_of_cache(dense):
     # req0 has nothing ahead; req1 sees req0's 10-token uncached tail, not
     # its gross 42-token prompt
     assert seen == [0, len(warm[0].prompt) - 32]
+
+
+# ---------------------------------------------------------------------------
+# decode writes the pool in place: the touched position of each slot's block
+
+
+def test_decode_writes_only_touched_blocks(dense):
+    """One decode step changes, in every layer, each active slot's write
+    position in its own block and nothing else; a free slot (table on the
+    null block, position 0) writes only the null block. Remapped tables and
+    positions reuse the one donated-pool executable."""
+    from repro.core.tracing import TraceStats, counting_jit
+    cfg, model, params = dense
+    bs, nb = 4, MAX_SEQ // 4
+    rng = np.random.default_rng(21)
+    n_pool = 2 * nb + 1
+    pool = tuple(jnp.asarray(rng.normal(size=c.shape), c.dtype)
+                 for c in model.init_cache(n_pool, bs))
+    tables = np.zeros((3, nb), np.int32)                 # slot 2: free
+    tables[:2] = rng.permutation(np.arange(1, n_pool)).reshape(2, nb)
+    stats = TraceStats()
+    step = counting_jit(make_paged_decode_step(model), "decode", stats,
+                        donate_argnums=(4,))
+    tokens = jnp.asarray([[3], [7], [0]], jnp.int32)
+    for pos in ([5, 13, 0], [6, 31, 0]):
+        before = [np.asarray(c, np.float32) for c in pool]
+        _, _, new = step(params, tokens, jnp.asarray(pos, jnp.int32),
+                         jnp.asarray(tables), pool)
+        assert all(c.is_deleted() for c in pool)         # donated
+        pool = new
+        touched = {(tables[s, p // bs], p % bs) for s, p in enumerate(pos)}
+        assert (PagePool.NULL, 0) in touched
+        for b4, c in zip(before, pool):
+            changed = np.argwhere((np.asarray(c, np.float32) != b4)
+                                  .any(axis=(3, 4)))     # [layer, blk, off]
+            got = {(int(blk), int(off)) for _, blk, off in changed}
+            assert got <= touched
+            for s in (0, 1):                             # every layer wrote
+                blk, off = tables[s, pos[s] // bs], pos[s] % bs
+                assert {int(li) for li, b, o in changed
+                        if (b, o) == (blk, off)} == set(range(cfg.num_layers))
+        tables[0] = np.roll(tables[0], 1)                # a remap
+    assert stats.compiles("decode") == 1
+
+
+def test_decode_reports_its_attention_and_kv_tokens(dense):
+    """Run stats name the decode attention as lowered (the reference off
+    a TPU), and each ``decode_step`` span counts the cache positions the
+    attention read: every slot's [0, pos]."""
+    cfg, model, params = dense
+    eng = ContinuousEngine(model, params, batch_size=2, max_seq=MAX_SEQ,
+                           telemetry=False)
+    stats = eng.serve(_mk_reqs(cfg, 2, plen=5, max_new=4))
+    assert stats["decode_attention"] == "reference"
+    kv = [s.attrs["kv_tokens"] for s in eng.tracer.spans()
+          if s.name == "decode_step"]
+    assert kv == [12, 14, 16]        # two slots from position 5, 3 steps

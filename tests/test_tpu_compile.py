@@ -61,8 +61,8 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args):
-    return jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, donate_argnums=()):
+    return jax.jit(fn, donate_argnums=donate_argnums).lower(*args).compile()
 
 
 def _assert_kernel(compiled):
@@ -126,6 +126,7 @@ def test_granite_paged_decode_step_compiles(one_chip, granite_paged):
     compiled = _compile(make_paged_decode_step(model), params,
                         i32(batch, 1), i32(batch), i32(batch, nb), pool)
     _fits_one_chip(compiled)
+    _assert_kernel(compiled)        # paged attention for MQA, 48:1
 
 
 def test_granite_bucketed_paged_prefill_compiles(one_chip, granite_paged):
@@ -134,3 +135,31 @@ def test_granite_bucketed_paged_prefill_compiles(one_chip, granite_paged):
     compiled = _compile(make_paged_slot_prefill(model, bucketed=True),
                         params, i32(1, 1024), i32(), i32(), i32(nb), pool)
     _fits_one_chip(compiled)
+
+
+def test_qwen3_paged_decode_step_attends_in_place(one_chip):
+    """qwen3-32b at published widths, 4 layers, served as the benchmark
+    serves it (bf16 weights, batch 32, max_seq 4096, block 32, 4097 pool
+    blocks): decode attention is the kernel, the donated pool is updated
+    in place, and no view of the cache is materialised."""
+    cfg = configs.get("qwen3-32b").replace(num_layers=4)
+    model = build_model(cfg)
+    params, _ = abstract_params(model)
+    params = jax.tree.map(lambda p: _sds(p.shape, jnp.bfloat16, one_chip),
+                          params)
+    batch, max_seq, block = 32, 4096, 32
+    nb = max_seq // block
+    pool = jax.eval_shape(lambda: model.init_cache(batch * nb + 1, block))
+    pool = jax.tree.map(lambda c: _sds(c.shape, c.dtype, one_chip), pool)
+    pool_bytes = sum(c.size * c.dtype.itemsize for c in pool)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)
+    compiled = _compile(make_paged_decode_step(model), params,
+                        i32(batch, 1), i32(batch), i32(batch, nb), pool,
+                        donate_argnums=(4,))
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert pool_bytes > 2.1e9
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    # the old step held a 2.15 GB view and its per-layer slices here, and
+    # an undonated or copied pool would show here too
+    assert mem.temp_size_in_bytes < 1e9, mem
